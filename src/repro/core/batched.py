@@ -9,8 +9,9 @@ for an entire wave in one batched call:
 * every (function, block) pair becomes a *row*: affinity vector ``aff[T]``
   (+1/-1/0), capacity threshold, concurrency bound, worker mask and rank;
 * worker state becomes ``occ[W, T]`` tag counts + memory/concurrency vectors;
-* one ``affinity_valid`` evaluation (Pallas kernel on TPU, jnp ref elsewhere)
-  yields ``valid[R, W]`` against the wave-start snapshot.
+* one ``affinity_valid`` evaluation (the backend the caller names: numpy
+  twin, jnp reference or the Pallas TPU kernel) yields ``valid[R, W]``
+  against the wave-start snapshot.
 
 Sequential exactness.  Listing 1 is inherently sequential: an allocation can
 flip validity for later functions (e.g. `impera` affine to `divide` placed in
@@ -608,7 +609,7 @@ def schedule_wave(
     reg: Registry,
     *,
     rng: Optional[random.Random] = None,
-    backend: str = "auto",
+    backend: str,
     apply_to: Optional[ClusterState] = None,
     warmth: Optional[Warmth] = None,
 ) -> WaveResult:
@@ -800,12 +801,13 @@ class SchedulerSession:
 
     def __init__(self, state: ClusterState, reg: Registry,
                  script=None, *,
-                 backend: str = "np", pool=None,
+                 backend: str = "np", interpret: bool = False, pool=None,
                  clock: Optional[Callable[[], float]] = None,
                  max_cached_scripts: int = 128):
         self.state = state
         self.reg = reg
         self.backend = backend
+        self.interpret = interpret  # pallas off the TPU: Pallas interpreter
         self.pool = pool
         self.clock = clock or (lambda: 0.0)
         self.tag_index = TagIndex([])
@@ -1097,7 +1099,7 @@ class SchedulerSession:
             valid = affinity_valid_np(
                 snap.occ, aff, wmask, snap.mem_used, snap.max_mem,
                 snap.n_funcs, f_mem, bank.cap, bank.conc,
-                backend=self.backend)  # [B, W]
+                backend=self.backend, interpret=self.interpret)  # [B, W]
         warm_vec, warmth_fn = self._resolve_warmth(f, warmth, snap)
         workers = snap.workers
         n_funcs = snap.n_funcs
@@ -1486,7 +1488,7 @@ class SchedulerSession:
                 snap.mem_used, snap.max_mem, snap.n_funcs,
                 np.concatenate(fmems), np.concatenate(caps),
                 np.concatenate(concs), np.concatenate(strats),
-                warm_all, backend=self.backend)
+                warm_all, backend=self.backend, interpret=self.interpret)
             score_all = np.asarray(score_all)
             r0 = 0
             for f, spec, bank, codes, wmask, warm_vec in ready:

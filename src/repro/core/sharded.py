@@ -104,13 +104,14 @@ class ShardedSession:
     """
 
     def __init__(self, state: ClusterState, reg: Registry, script=None, *,
-                 backend: str = "np", pool=None,
+                 backend: str = "np", interpret: bool = False, pool=None,
                  clock: Optional[Callable[[], float]] = None,
                  zone_strategy: str = "local_first",
                  max_cached_scripts: int = 128):
         self.state = state
         self.reg = reg
         self.backend = backend
+        self.interpret = interpret
         self.pool = pool
         self.clock = clock or (lambda: 0.0)
         self.zone_strategy = zone_strategy
@@ -118,7 +119,8 @@ class ShardedSession:
         #: the flat whole-cluster session: the delegation target for
         #: zone-free decisions and the reference the property tests pin
         self.flat = SchedulerSession(state, reg, script, backend=backend,
-                                     pool=pool, clock=self.clock,
+                                     interpret=interpret, pool=pool,
+                                     clock=self.clock,
                                      max_cached_scripts=max_cached_scripts)
         self._shards: Dict[str, SchedulerSession] = {}
         self._plans: "OrderedDict[AAppScript, ZonePlan]" = OrderedDict()
@@ -208,7 +210,7 @@ class ShardedSession:
         if got is None:
             got = SchedulerSession(
                 ZoneView(self.state, zone), self.reg, backend=self.backend,
-                pool=self.pool, clock=self.clock,
+                interpret=self.interpret, pool=self.pool, clock=self.clock,
                 max_cached_scripts=self._max_cached_scripts)
             if self._obs is not None:
                 got.attach_obs(self._obs)

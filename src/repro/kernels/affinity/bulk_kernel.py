@@ -35,9 +35,9 @@ def _bulk_decide_kernel(
     conc_ref,  # [BF, 1] i32
     strat_ref,  # [BF, 1] i32 strategy code
     occ_ref,  # [BW, T] i32
-    mem_ref,  # [BW, 1] f32
-    maxm_ref,  # [BW, 1] f32
-    nfn_ref,  # [BW, 1] i32
+    mem_ref,  # [1, BW] f32
+    maxm_ref,  # [1, BW] f32
+    nfn_ref,  # [1, BW] i32
     wmask_ref,  # [BF, BW] int8
     warm_ref,  # [BF, BW] i32 warmth rank
     valid_ref,  # [BF, BW] int8 out
@@ -46,7 +46,7 @@ def _bulk_decide_kernel(
     minidx_ref,  # [BF, 1] i32 out
 ):
     j = pl.program_id(1)
-    aff = aff_ref[...]
+    aff = aff_ref[...].astype(jnp.int32)  # no int8 vector compare on TPU
     occ = occ_ref[...]
 
     empty = (occ == 0).astype(jnp.float32)  # [BW, T]
@@ -63,15 +63,15 @@ def _bulk_decide_kernel(
     )  # [BF, BW]
     ok_aff = violations == 0.0
 
-    mem_used = mem_ref[...].reshape(1, -1)  # [1, BW]
-    max_mem = maxm_ref[...].reshape(1, -1)
-    n_funcs = nfn_ref[...].reshape(1, -1)
+    mem_used = mem_ref[...]  # [1, BW], lane-major
+    max_mem = maxm_ref[...]
+    n_funcs = nfn_ref[...]
     f_mem = fmem_ref[...]  # [BF, 1]
 
     ok_fit = mem_used + f_mem <= max_mem
     ok_cap = mem_used < cap_ref[...] * 0.01 * max_mem
     ok_conc = n_funcs < conc_ref[...]
-    ok_w = wmask_ref[...] != 0
+    ok_w = wmask_ref[...].astype(jnp.int32) != 0
     valid = ok_aff & ok_fit & ok_cap & ok_conc & ok_w
 
     rank = jnp.clip(warm_ref[...], 0, 2)  # [BF, BW]
@@ -121,12 +121,13 @@ def bulk_decide_kernel(
     of 128.
 
     Shapes: aff[R,T] i8, f_mem/cap_pct[R,1] f32, max_conc/strat[R,1] i32,
-    occ[W,T] i32, mem_used/max_mem[W,1] f32, n_funcs[W,1] i32,
+    occ[W,T] i32, mem_used/max_mem[1,W] f32, n_funcs[1,W] i32,
     wmask[R,W] i8, warm[R,W] i32 -> (valid[R,W] i8, score[R,W] f32,
     minval[R,1] f32, minidx[R,1] i32).
     """
     R, T = aff.shape
     W = occ.shape[0]
+    assert mem_used.shape == max_mem.shape == n_funcs.shape == (1, W)
     assert R % BF == 0 and W % BW == 0 and T % T_ALIGN == 0, (R, W, T)
     grid = (R // BF, W // BW)
 
@@ -140,9 +141,9 @@ def bulk_decide_kernel(
             pl.BlockSpec((BF, 1), lambda i, j: (i, 0)),  # max_conc
             pl.BlockSpec((BF, 1), lambda i, j: (i, 0)),  # strat
             pl.BlockSpec((BW, T), lambda i, j: (j, 0)),  # occ
-            pl.BlockSpec((BW, 1), lambda i, j: (j, 0)),  # mem_used
-            pl.BlockSpec((BW, 1), lambda i, j: (j, 0)),  # max_mem
-            pl.BlockSpec((BW, 1), lambda i, j: (j, 0)),  # n_funcs
+            pl.BlockSpec((1, BW), lambda i, j: (0, j)),  # mem_used
+            pl.BlockSpec((1, BW), lambda i, j: (0, j)),  # max_mem
+            pl.BlockSpec((1, BW), lambda i, j: (0, j)),  # n_funcs
             pl.BlockSpec((BF, BW), lambda i, j: (i, j)),  # wmask
             pl.BlockSpec((BF, BW), lambda i, j: (i, j)),  # warm
         ],

@@ -1,7 +1,9 @@
-"""jit'd public wrapper around the fused bulk decide kernel: padding,
-backend pick, unpadding — the bulk twin of :mod:`.ops`.  Without JAX the
-host entry degrades to the pure-numpy twin so the group-commit batching
-front end stays fully functional in minimal environments.
+"""Public wrapper around the fused bulk decide kernel: padding, backend
+dispatch, unpadding — the bulk twin of :mod:`.ops`, with the same rule:
+the caller names the backend, and ``pallas`` off the TPU needs
+``interpret=True``.  Without JAX the host entry still serves
+``backend="np"``, the pure-numpy twin, so the group-commit batching front
+end stays fully functional in minimal environments.
 """
 from __future__ import annotations
 
@@ -17,6 +19,7 @@ try:
     from .bulk_kernel import bulk_decide_kernel
     from .bulk_ref import bulk_decide_ref
     from .kernel import BF, BW, T_ALIGN
+    from .ops import round_up, check_pallas, pad_to
 
     # steady-state entry: one traced XLA program per (R, W, T) shape class
     # instead of ~30 eager op dispatches per wave
@@ -25,10 +28,6 @@ try:
     HAS_JAX = True
 except ImportError:  # minimal environment: numpy twin only
     HAS_JAX = False
-
-
-def _round_up(x: int, m: int) -> int:
-    return ((x + m - 1) // m) * m
 
 
 def _fill(R: int, W: int, strat, warm):
@@ -52,13 +51,14 @@ def bulk_decide(
     strat=None,
     warm=None,
     *,
-    backend: str = "auto",
+    backend: str,
+    interpret: bool = False,
 ):
     """Fused bulk decide: returns ``(valid[R, W] bool, score[R, W] f32,
     winner[R] i32)`` with ``winner == -1`` when a row has no valid worker.
 
-    ``backend``: ``auto`` (pallas on TPU, ref elsewhere), ``pallas``
-    (interpret-mode off-TPU — used by tests), or ``ref``.
+    ``backend``: ``pallas`` (the TPU kernel; ``interpret=True`` runs it in
+    the Pallas interpreter on any backend) or ``ref`` (the jnp reference).
     """
     if not HAS_JAX:
         raise ImportError(
@@ -77,44 +77,37 @@ def bulk_decide(
         max_conc = np.full((R,), NO_CONC, np.int32)
     strat, warm = _fill(R, W, strat, warm)
 
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
     if backend == "ref":
         return _bulk_ref_jit(
             occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem,
             cap_pct, max_conc, strat, warm)
     if backend != "pallas":
         raise ValueError(f"unknown backend {backend!r}")
+    check_pallas(interpret)
 
-    interpret = jax.default_backend() != "tpu"
-    Rp = _round_up(max(R, 1), BF)
-    Wp = _round_up(max(W, 1), BW)
-    Tp = _round_up(max(T, 1), T_ALIGN)
+    Rp = round_up(max(R, 1), BF)
+    Wp = round_up(max(W, 1), BW)
+    Tp = round_up(max(T, 1), T_ALIGN)
 
-    occ_p = jnp.zeros((Wp, Tp), jnp.int32).at[:W, :T].set(occ)
-    aff_p = jnp.zeros((Rp, Tp), jnp.int8).at[:R, :T].set(aff)
-    wmask_p = jnp.zeros((Rp, Wp), jnp.int8).at[:R, :W].set(
-        jnp.asarray(wmask, jnp.int8))
-    warm_p = jnp.zeros((Rp, Wp), jnp.int32).at[:R, :W].set(
-        jnp.asarray(warm, jnp.int32))
-    mem_p = jnp.zeros((Wp, 1), jnp.float32).at[:W, 0].set(
-        jnp.asarray(mem_used, jnp.float32))
-    maxm_p = jnp.zeros((Wp, 1), jnp.float32).at[:W, 0].set(
-        jnp.asarray(max_mem, jnp.float32))
-    nfn_p = jnp.zeros((Wp, 1), jnp.int32).at[:W, 0].set(
-        jnp.asarray(n_funcs, jnp.int32))
-    fmem_p = jnp.zeros((Rp, 1), jnp.float32).at[:R, 0].set(
-        jnp.asarray(f_mem, jnp.float32))
-    cap_p = jnp.full((Rp, 1), NO_CAP, jnp.float32).at[:R, 0].set(
-        jnp.asarray(cap_pct, jnp.float32))
-    conc_p = jnp.full((Rp, 1), NO_CONC, jnp.int32).at[:R, 0].set(
-        jnp.asarray(max_conc, jnp.int32))
-    strat_p = jnp.zeros((Rp, 1), jnp.int32).at[:R, 0].set(
-        jnp.asarray(strat, jnp.int32))
+    def rows(x, dtype, fill=0):  # per-row column [R] -> [Rp, 1]
+        return pad_to(np.reshape(x, (R, 1)), (Rp, 1), dtype, fill)
+
+    def lanes(x, dtype):  # per-worker vector [W] -> lane-major [1, Wp]
+        return pad_to(np.reshape(x, (1, W)), (1, Wp), dtype)
 
     valid, score, minval, minidx = bulk_decide_kernel(
-        aff_p, fmem_p, cap_p, conc_p, strat_p, occ_p, mem_p, maxm_p, nfn_p,
-        wmask_p, warm_p, interpret=interpret)
+        pad_to(aff, (Rp, Tp), np.int8),
+        rows(f_mem, np.float32),
+        rows(cap_pct, np.float32, NO_CAP),
+        rows(max_conc, np.int32, NO_CONC),
+        rows(strat, np.int32),
+        pad_to(occ, (Wp, Tp), np.int32),
+        lanes(mem_used, np.float32),
+        lanes(max_mem, np.float32),
+        lanes(n_funcs, np.int32),
+        pad_to(wmask, (Rp, Wp), np.int8),
+        pad_to(warm, (Rp, Wp), np.int32),
+        interpret=interpret)
     winner = jnp.where(jnp.isinf(minval[:R, 0]), -1,
                        minidx[:R, 0]).astype(jnp.int32)
     return valid[:R, :W].astype(bool), score[:R, :W], winner
@@ -133,19 +126,23 @@ def bulk_decide_np(
     strat=None,
     warm=None,
     *,
-    backend: str = "auto",
+    backend: str,
+    interpret: bool = False,
 ):
-    """Host-side convenience: numpy in/out.  Runs the pure-numpy twin when
-    JAX is unavailable (``auto``/``ref``/``np`` backends only), or always
-    with ``backend="np"`` — the exact-arithmetic (float64 score) path the
-    incremental session uses."""
-    if HAS_JAX and backend != "np":
+    """Host-side convenience: numpy in/out.  ``backend="np"`` runs the
+    pure-numpy twin — the exact-arithmetic (float64 score) path the
+    incremental session uses, which also stands in for ``ref`` without
+    JAX; ``ref``/``pallas`` go to :func:`bulk_decide`."""
+    if backend == "pallas" or (backend != "np" and HAS_JAX):
+        if not HAS_JAX:
+            raise ImportError("backend 'pallas' requires JAX")
         valid, score, winner = bulk_decide(
             occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem,
-            cap_pct, max_conc, strat, warm, backend=backend)
+            cap_pct, max_conc, strat, warm, backend=backend,
+            interpret=interpret)
         return np.asarray(valid), np.asarray(score), np.asarray(winner)
-    if backend not in ("auto", "ref", "np"):
-        raise ImportError(f"backend {backend!r} requires JAX")
+    if backend not in ("np", "ref"):
+        raise ValueError(f"unknown backend {backend!r}")
     R = np.asarray(aff).shape[0]
     W = np.asarray(occ).shape[0]
     if cap_pct is None:

@@ -4,8 +4,14 @@ Grid: (F / BF, W / BW).  Per grid cell the kernel holds in VMEM:
 
 * ``aff``   block  [BF, T]   int8   (the pending functions' affinity rows)
 * ``occ``   block  [BW, T]   int32  (the workers' tag occupancy)
-* 1-wide row/col vectors for memory/concurrency terms
+* per-function ``[BF, 1]`` columns and lane-major per-worker ``[1, BW]``
+  rows for the memory/concurrency terms
 * ``valid`` output [BF, BW]  int8
+
+int8 operands are widened to int32 before any compare: Mosaic has no int8
+vector comparison on TPU.  The per-worker vectors arrive as ``[1, W]`` so
+their blocks need no in-kernel transpose (a ``[BW, 1]`` -> ``[1, BW]``
+reshape blows the scoped VMEM limit).
 
 The affinity check is MXU work: with ``pos = (aff==1)`` and ``neg = (aff==-1)``
 as f32 masks, ``violations = pos @ empty.T + neg @ present.T`` is two
@@ -36,13 +42,13 @@ def _affinity_kernel(
     cap_ref,  # [BF, 1] f32 (percent, NO_CAP sentinel when absent)
     conc_ref,  # [BF, 1] i32
     occ_ref,  # [BW, T] i32
-    mem_ref,  # [BW, 1] f32 (memory_used)
-    maxm_ref,  # [BW, 1] f32 (max_memory)
-    nfn_ref,  # [BW, 1] i32
+    mem_ref,  # [1, BW] f32 (memory_used)
+    maxm_ref,  # [1, BW] f32 (max_memory)
+    nfn_ref,  # [1, BW] i32
     wmask_ref,  # [BF, BW] int8
     valid_ref,  # [BF, BW] int8 out
 ):
-    aff = aff_ref[...]
+    aff = aff_ref[...].astype(jnp.int32)
     occ = occ_ref[...]
 
     empty = (occ == 0).astype(jnp.float32)  # [BW, T]
@@ -63,9 +69,9 @@ def _affinity_kernel(
     )  # [BF, BW]
     ok_aff = violations == 0.0
 
-    mem_used = mem_ref[...].reshape(1, -1)  # [1, BW]
-    max_mem = maxm_ref[...].reshape(1, -1)
-    n_funcs = nfn_ref[...].reshape(1, -1)
+    mem_used = mem_ref[...]  # [1, BW]
+    max_mem = maxm_ref[...]
+    n_funcs = nfn_ref[...]
     f_mem = fmem_ref[...]  # [BF, 1]
     cap = cap_ref[...]
     conc = conc_ref[...]
@@ -73,7 +79,7 @@ def _affinity_kernel(
     ok_fit = mem_used + f_mem <= max_mem
     ok_cap = mem_used < cap * 0.01 * max_mem
     ok_conc = n_funcs < conc
-    ok_w = wmask_ref[...] != 0
+    ok_w = wmask_ref[...].astype(jnp.int32) != 0
 
     valid = ok_aff & ok_fit & ok_cap & ok_conc & ok_w
     valid_ref[...] = valid.astype(jnp.int8)
@@ -86,11 +92,12 @@ def affinity_valid_kernel(
     """Padded-shape entry point: F, W multiples of (BF, BW); T multiple of 128.
 
     Shapes: aff[F,T] i8, f_mem/cap_pct[F,1] f32, max_conc[F,1] i32,
-    occ[W,T] i32, mem_used/max_mem[W,1] f32, n_funcs[W,1] i32,
+    occ[W,T] i32, mem_used/max_mem[1,W] f32, n_funcs[1,W] i32,
     wmask[F,W] i8 -> valid[F,W] i8.
     """
     F, T = aff.shape
     W = occ.shape[0]
+    assert mem_used.shape == max_mem.shape == n_funcs.shape == (1, W)
     assert F % BF == 0 and W % BW == 0 and T % T_ALIGN == 0, (F, W, T)
     grid = (F // BF, W // BW)
 
@@ -103,9 +110,9 @@ def affinity_valid_kernel(
             pl.BlockSpec((BF, 1), lambda i, j: (i, 0)),  # cap_pct
             pl.BlockSpec((BF, 1), lambda i, j: (i, 0)),  # max_conc
             pl.BlockSpec((BW, T), lambda i, j: (j, 0)),  # occ
-            pl.BlockSpec((BW, 1), lambda i, j: (j, 0)),  # mem_used
-            pl.BlockSpec((BW, 1), lambda i, j: (j, 0)),  # max_mem
-            pl.BlockSpec((BW, 1), lambda i, j: (j, 0)),  # n_funcs
+            pl.BlockSpec((1, BW), lambda i, j: (0, j)),  # mem_used
+            pl.BlockSpec((1, BW), lambda i, j: (0, j)),  # max_mem
+            pl.BlockSpec((1, BW), lambda i, j: (0, j)),  # n_funcs
             pl.BlockSpec((BF, BW), lambda i, j: (i, j)),  # wmask
         ],
         out_specs=pl.BlockSpec((BF, BW), lambda i, j: (i, j)),

@@ -1,6 +1,9 @@
 import os
+# A CPU-only tool on placeholder devices: pinned to the CPU (children
+# inherit the environment) so that it never takes an accelerator.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import (device count locks at
+# The lines above MUST run before any jax import (device count locks at
 # first init).  Tests may shrink the placeholder pool via REPRO_DRYRUN_DEVICES.
 if os.environ.get("REPRO_DRYRUN_DEVICES"):
     os.environ["XLA_FLAGS"] = (
@@ -21,7 +24,8 @@ import jax.numpy as jnp
 
 from repro.configs import ARCHS, SHAPES, get_arch, param_counts, shape_applicable
 from repro.launch.inputs import input_specs
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh
+from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_mesh,
+                               make_production_mesh)
 from repro.models.model import model_flops_per_token, params_shape
 from repro.optim import adamw
 from repro.roofline import flops as hlo_flops
@@ -71,7 +75,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *, reduced: bool = Fal
     if reduced:  # CI smoke: tiny mesh on the shrunken device pool
         shape_ax = (2, 2, 2) if multi_pod else (2, 2)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-        mesh = jax.make_mesh(shape_ax, axes)
+        mesh = make_mesh(shape_ax, axes)
         rec["mesh"] = "x".join(map(str, shape_ax))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)

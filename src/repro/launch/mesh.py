@@ -8,12 +8,19 @@ import to obtain placeholder devices; smoke tests and benches see 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the model code places arrays with
+    ``with_sharding_constraint`` and leaves the rest to the partitioner."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh_for(n_devices: int, *, model_axis: int = None):
@@ -25,7 +32,7 @@ def make_mesh_for(n_devices: int, *, model_axis: int = None):
                 model_axis = cand
                 break
     assert n_devices % model_axis == 0, (n_devices, model_axis)
-    return jax.make_mesh((n_devices // model_axis, model_axis), ("data", "model"))
+    return make_mesh((n_devices // model_axis, model_axis), ("data", "model"))
 
 
 # TPU v5e roofline constants (per chip)

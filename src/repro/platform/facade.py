@@ -84,6 +84,7 @@ class Platform:
         seed: int = 0,
         clock: Optional[Callable[[], float]] = None,
         backend: str = "np",
+        interpret: bool = False,
         zones: Optional[Mapping[str, object]] = None,
         zone_strategy: str = "local_first",
         shard_floor: int = 1024,
@@ -124,6 +125,7 @@ class Platform:
         # plane *delegates* zone-free decisions to its flat sub-session
         # (property-tested)
         self._backend = backend
+        self._interpret = interpret
         self._zone_strategy = zone_strategy
         self.shard_floor = shard_floor
         self._sharded = len(zone_set) > 1 and (
@@ -133,13 +135,14 @@ class Platform:
             self.session: SchedulerSession = ShardedSession(
                 self.state, self.registry,
                 self.compiled if self.compiled is not None else None,
-                backend=backend, pool=pool, clock=self.clock,
-                zone_strategy=zone_strategy)
+                backend=backend, interpret=interpret, pool=pool,
+                clock=self.clock, zone_strategy=zone_strategy)
         else:
             self.session = SchedulerSession(
                 self.state, self.registry,
                 self.compiled if self.compiled is not None else None,
-                backend=backend, pool=pool, clock=self.clock)
+                backend=backend, interpret=interpret, pool=pool,
+                clock=self.clock)
         self._containers: Dict[str, str] = {}  # activation id -> container id
         # observability plane (repro.obs.Obs): the tracer reference is
         # cached so the disabled hot path pays one attribute load + None
@@ -650,7 +653,8 @@ class Platform:
             self._sharded = True
             self.session = ShardedSession(
                 self.state, self.registry, compiled,
-                backend=self._backend, pool=self.pool, clock=self.clock,
+                backend=self._backend, interpret=self._interpret,
+                pool=self.pool, clock=self.clock,
                 zone_strategy=self._zone_strategy)
             if self.obs is not None:
                 self.session.attach_obs(self.obs)

@@ -92,7 +92,7 @@ def test_bulk_backends_agree(W, T, R):
     args = _case(W, T, R, seed=W * 100003 + T * 101 + R)
     v_np, _s_np, w_np = _np_oracle(args)
     v_rf, s_rf, w_rf = bulk_decide_np(*args, backend="ref")
-    v_pl, s_pl, w_pl = bulk_decide_np(*args, backend="pallas")
+    v_pl, s_pl, w_pl = bulk_decide_np(*args, backend="pallas", interpret=True)
     np.testing.assert_array_equal(v_np, v_rf)
     np.testing.assert_array_equal(v_rf, v_pl)
     np.testing.assert_array_equal(np.asarray(w_np), np.asarray(w_rf))
@@ -114,6 +114,29 @@ def test_bulk_winner_is_first_valid_minimum():
             assert winner[r] == int(np.argmin(row))
             # first-minimum: no earlier worker ties the winner
             assert not (row[:winner[r]] == row[winner[r]]).any()
+
+
+@needs_jax
+@pytest.mark.parametrize("entry", ["bulk", "valid"])
+def test_pallas_off_tpu_needs_interpret_and_auto_is_gone(entry):
+    """No silent fallback: ``pallas`` on a non-TPU backend raises unless the
+    caller asks for the interpreter, and no backend is picked for the
+    caller."""
+    import jax
+
+    assert jax.default_backend() != "tpu"
+    args = _case(5, 3, 4, seed=1)
+    if entry == "bulk":
+        call = bulk_decide_np
+    else:
+        args = args[:9]
+        call = affinity_valid_np
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        call(*args, backend="pallas")
+    with pytest.raises(ValueError, match="unknown backend 'auto'"):
+        call(*args, backend="auto")
+    with pytest.raises(TypeError):
+        call(*args)  # the backend has no default
 
 
 def test_bulk_np_twin_runs_without_jax_guard():
@@ -151,9 +174,10 @@ if HAS_HYPOTHESIS:
             _strat, _warm = _case(W, T, R, seed)
         args = (occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem,
                 cap, conc)
-        v_np = affinity_valid_np(*args)
+        v_np = affinity_valid_np(*args, backend="np")
         v_rf = np.asarray(affinity_valid(*args, backend="ref"))
-        v_pl = np.asarray(affinity_valid(*args, backend="pallas"))
+        v_pl = np.asarray(affinity_valid(*args, backend="pallas",
+                                          interpret=True))
         np.testing.assert_array_equal(v_np, v_rf)
         np.testing.assert_array_equal(v_rf, v_pl)
 
@@ -166,7 +190,7 @@ if HAS_HYPOTHESIS:
         args = _case(W, T, R, seed)
         v_np, _s, w_np = _np_oracle(args)
         v_rf, _s, w_rf = bulk_decide_np(*args, backend="ref")
-        v_pl, _s, w_pl = bulk_decide_np(*args, backend="pallas")
+        v_pl, _s, w_pl = bulk_decide_np(*args, backend="pallas", interpret=True)
         np.testing.assert_array_equal(v_np, v_rf)
         np.testing.assert_array_equal(v_rf, v_pl)
         np.testing.assert_array_equal(np.asarray(w_np), np.asarray(w_rf))

@@ -32,7 +32,7 @@ def test_affinity_kernel_matches_ref(W, T, F):
     conc = np.where(rng.random(F) > 0.5, 10, 2**30).astype(np.int32)
     args = (occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem, cap, conc)
     ref = np.asarray(affinity_valid(*args, backend="ref"))
-    out = np.asarray(affinity_valid(*args, backend="pallas"))
+    out = np.asarray(affinity_valid(*args, backend="pallas", interpret=True))
     np.testing.assert_array_equal(ref, out)
 
 
@@ -49,7 +49,7 @@ def test_affinity_kernel_property(W, T, F, seed):
     n_funcs = np.zeros(W, np.int32)
     f_mem = np.zeros(F, np.float32)
     out = np.asarray(affinity_valid(occ, aff, wmask, mem_used, max_mem, n_funcs,
-                                    f_mem, backend="pallas"))
+                                    f_mem, backend="pallas", interpret=True))
     # brute-force oracle
     for f in range(F):
         for w in range(W):

@@ -81,7 +81,7 @@ def test_custom_strategy_registers_and_schedules_everywhere():
         assert session.try_schedule("fn") == "w2"
         session.close()
         res = schedule_wave(["fn"], state.conf(),
-                            CompiledPolicies(script, reg), reg)
+                            CompiledPolicies(script, reg), reg, backend="ref")
         assert res.assignments == ["w2"]
     finally:
         # the registry is process-global: drop the test strategy again
@@ -312,5 +312,5 @@ def test_new_strategies_wave_equals_scalar():
 
         res = schedule_wave(fs, state.conf(), CompiledPolicies(script, reg),
                             reg, rng=random.Random(seed * 7 + 1),
-                            warmth=warmth)
+                            backend="ref", warmth=warmth)
         assert res.assignments == expected, (seed, res.assignments, expected)
