@@ -881,16 +881,10 @@ class SchedulerSession:
         if self._snap is None:
             return
         tm = self._timers
-        if tm is not None:
-            # inlined tm.sample(): this fires on every state mutation, so
-            # the unsampled passes pay only the counter advance
-            t = (tm.tick + 1) & tm.mask
-            tm.tick = t
-            if t == 0:
-                t0 = perf_counter()
+        if tm is not None and tm.sample():
+            with tm.span("delta_apply"):
                 self._apply_event(kind, payload)
-                tm.observe("delta_apply", perf_counter() - t0)
-                return
+            return
         self._apply_event(kind, payload)
 
     def _apply_event(self, kind: str, payload: Dict) -> None:
@@ -1060,8 +1054,21 @@ class SchedulerSession:
         """One Listing-1 decision on the live tensors.  ``only`` (internal,
         used by the sharded router) restricts the scan to a subset of the
         tag's bank rows, in the given order — Listing-1 semantics over a
-        router-chosen slice of the chain."""
+        router-chosen slice of the chain.  A sampled decision runs in the
+        ``item_decide`` span, labelled with this session's decision count,
+        and times its stages inside it."""
         self.stats["decisions"] += 1
+        tm = self._timers
+        if tm is not None and tm.sample():
+            with tm.span("item_decide", decision=self.stats["decisions"]):
+                return self._decide_item(f, pol, snap, rng, warmth, only, tm)
+        return self._decide_item(f, pol, snap, rng, warmth, only, None)
+
+    def _decide_item(self, f: str, pol: CompiledPolicies,
+                     snap: StateTensors, rng, warmth,
+                     only: Optional[Sequence[int]], tm) -> Optional[str]:
+        """:meth:`_decide`'s body; ``tm`` is the stage timers when this
+        decision is timed, else ``None``."""
         spec = self.reg[f]  # raises KeyError like the scalar reference
         W = len(snap.workers)
         bank = pol.rows_for(spec.tag)
@@ -1076,20 +1083,9 @@ class SchedulerSession:
                 [aff, np.zeros((B, snap.occ.shape[1] - T), np.int8)], axis=1)
             bank.aff = aff
             bank._derive()
-        tm = self._timers
-        # one sampled gate per decision: when it fires, both decision-path
-        # stages (mask build, strategy select) are timed.  Inlined
-        # tm.sample() — a method call here is measurable against the
-        # enabled-path budget
-        timed = False
         if tm is not None:
-            _tk = (tm.tick + 1) & tm.mask
-            tm.tick = _tk
-            timed = _tk == 0
-        if timed:
-            _t0 = perf_counter()
-            wmask = self._wmask(pol, spec.tag, bank, snap)
-            tm.observe("mask_build", perf_counter() - _t0)
+            with tm.span("mask_build"):
+                wmask = self._wmask(pol, spec.tag, bank, snap)
         else:
             wmask = self._wmask(pol, spec.tag, bank, snap)
         if self.backend == "np":
@@ -1099,7 +1095,8 @@ class SchedulerSession:
             valid = affinity_valid_np(
                 snap.occ, aff, wmask, snap.mem_used, snap.max_mem,
                 snap.n_funcs, f_mem, bank.cap, bank.conc,
-                backend=self.backend, interpret=self.interpret)  # [B, W]
+                backend=self.backend, interpret=self.interpret,
+                timers=tm)  # [B, W]
         warm_vec, warmth_fn = self._resolve_warmth(f, warmth, snap)
         workers = snap.workers
         n_funcs = snap.n_funcs
@@ -1153,10 +1150,9 @@ class SchedulerSession:
                     ranks = [rank_of(j) for j in cand]
                     best = max(ranks)
                     cand = [j for j, r in zip(cand, ranks) if r == best]
-            if timed:
-                _t0 = perf_counter()
-                jj = int(strat.select(cand, ctx, rng))
-                tm.observe("strategy_select", perf_counter() - _t0)
+            if tm is not None:
+                with tm.span("strategy_select"):
+                    jj = int(strat.select(cand, ctx, rng))
             else:
                 jj = int(strat.select(cand, ctx, rng))
             w = workers[jj]

@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import random
 from collections import OrderedDict
-from time import perf_counter
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from .ast import AAppScript
@@ -287,14 +286,20 @@ class ShardedSession:
                                           warmth=warmth)
         self.stats["routed"] += 1
         rng = rng if rng is not None else default_rng()
+        tm = self._timers
+        if tm is not None and tm.sample():
+            with tm.span("shard_route", decision=self.stats["decisions"]):
+                return self._route(f, plan, tag, rng, warmth, origin_zone)
+        return self._route(f, plan, tag, rng, warmth, origin_zone)
+
+    def _route(self, f: str, plan: ZonePlan, tag: str, rng, warmth,
+               origin_zone: Optional[str]) -> Optional[str]:
+        """The two-level route of one decision of a routed chain: per block,
+        the zones its terms admit in the hint's order, each tried by a
+        decision on that zone's shard until one places."""
         chain = plan.chain(tag)
         stats = self.stats
         tr = self._tracer
-        tm = self._timers
-        if tm is not None and not tm.sample():
-            tm = None  # unsampled pass: route untimed
-        if tm is not None:
-            _t0 = perf_counter()
         masks = plan.mask(tag)
         nz = len(plan.zones)
         # route trace (tracer on only): per evaluated block the admitted
@@ -326,8 +331,6 @@ class ShardedSession:
                     tried.append((bi, z))
             if w is not None:
                 break
-        if tm is not None:
-            tm.observe("shard_route", perf_counter() - _t0)
         if tr is not None:
             tr.route(self.clock(), f, tag, hint, tuple(admitted),
                      tuple(tried), stats["zone_hops"] - hops0,

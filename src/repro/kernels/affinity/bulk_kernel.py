@@ -160,5 +160,6 @@ def bulk_decide_kernel(
             jax.ShapeDtypeStruct((R, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="bulk_decide_kernel",  # the device trace's op name, which bench/ reads
     )(aff, f_mem, cap_pct, max_conc, strat, occ, mem_used, max_mem, n_funcs,
       wmask, warm)

@@ -118,4 +118,5 @@ def affinity_valid_kernel(
         out_specs=pl.BlockSpec((BF, BW), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((F, W), jnp.int8),
         interpret=interpret,
+        name="affinity_valid_kernel",  # the device trace's op name, which bench/ reads
     )(aff, f_mem, cap_pct, max_conc, occ, mem_used, max_mem, n_funcs, wmask)

@@ -69,16 +69,7 @@ def affinity_valid(
         raise ImportError(
             "affinity_valid requires JAX; use affinity_valid_np for the "
             "numpy fallback")
-    W, T = np.shape(occ)
-    F = np.shape(aff)[0]
-    if np.shape(aff)[1] != T:
-        raise ValueError(f"tag axes differ: occ {T}, aff {np.shape(aff)[1]}")
-
-    if cap_pct is None:
-        cap_pct = np.full((F,), NO_CAP, np.float32)
-    if max_conc is None:
-        max_conc = np.full((F,), NO_CONC, np.int32)
-
+    F, W, cap_pct, max_conc = _checked(occ, aff, cap_pct, max_conc)
     if backend == "ref":
         return affinity_valid_ref(
             occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem, cap_pct, max_conc
@@ -86,9 +77,35 @@ def affinity_valid(
     if backend != "pallas":
         raise ValueError(f"unknown backend {backend!r}")
     check_pallas(interpret)
-
-    Fp, Wp, Tp = round_up(max(F, 1), BF), round_up(max(W, 1), BW), round_up(max(T, 1), T_ALIGN)
     valid = affinity_valid_kernel(
+        *_padded(occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem,
+                 cap_pct, max_conc),
+        interpret=interpret,
+    )
+    return valid[:F, :W].astype(bool)
+
+
+def _checked(occ, aff, cap_pct, max_conc):
+    """``F``, ``W`` and the per-row limits, unlimited where not given."""
+    W, T = np.shape(occ)
+    F = np.shape(aff)[0]
+    if np.shape(aff)[1] != T:
+        raise ValueError(f"tag axes differ: occ {T}, aff {np.shape(aff)[1]}")
+    if cap_pct is None:
+        cap_pct = np.full((F,), NO_CAP, np.float32)
+    if max_conc is None:
+        max_conc = np.full((F,), NO_CONC, np.int32)
+    return F, W, cap_pct, max_conc
+
+
+def _padded(occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem, cap_pct,
+            max_conc):
+    """The kernel's nine inputs, padded on the host to tile-aligned
+    shapes."""
+    W, T = np.shape(occ)
+    F = np.shape(aff)[0]
+    Fp, Wp, Tp = round_up(max(F, 1), BF), round_up(max(W, 1), BW), round_up(max(T, 1), T_ALIGN)
+    return (
         pad_to(aff, (Fp, Tp), np.int8),
         pad_to(np.reshape(f_mem, (F, 1)), (Fp, 1), np.float32),
         pad_to(np.reshape(cap_pct, (F, 1)), (Fp, 1), np.float32, NO_CAP),
@@ -98,9 +115,28 @@ def affinity_valid(
         pad_to(np.reshape(max_mem, (1, W)), (1, Wp), np.float32),
         pad_to(np.reshape(n_funcs, (1, W)), (1, Wp), np.int32),
         pad_to(wmask, (Fp, Wp), np.int8),
-        interpret=interpret,
     )
-    return valid[:F, :W].astype(bool)
+
+
+def _timed_pallas(occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem,
+                  cap_pct, max_conc, interpret: bool, tm) -> np.ndarray:
+    """:func:`affinity_valid`'s Pallas path to a host array, its stages
+    timed by ``tm`` (a :class:`repro.obs.StageTimers`): ``valid_pad`` (host
+    padding), ``valid_launch`` (the kernel call up to its return: transfers
+    of the padded inputs and dispatch), ``valid_fetch`` (device wait, unpad,
+    copy to the host); counters ``valid_launches`` and ``valid_h2d_bytes``
+    (the padded inputs' bytes)."""
+    F, W, cap_pct, max_conc = _checked(occ, aff, cap_pct, max_conc)
+    check_pallas(interpret)
+    with tm.span("valid_pad"):
+        args = _padded(occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem,
+                       cap_pct, max_conc)
+    tm.count("valid_launches")
+    tm.count("valid_h2d_bytes", sum(a.nbytes for a in args))
+    with tm.span("valid_launch"):
+        valid = affinity_valid_kernel(*args, interpret=interpret)
+    with tm.span("valid_fetch"):
+        return np.asarray(valid[:F, :W].astype(bool))
 
 
 def affinity_valid_np(
@@ -116,15 +152,20 @@ def affinity_valid_np(
     *,
     backend: str,
     interpret: bool = False,
+    timers=None,
 ) -> np.ndarray:
     """Host-side convenience: numpy in/out.  ``backend="np"`` runs the
     pure-numpy reference — the zero-dispatch CPU hot path the incremental
     scheduling session uses (bit-identical to the jnp reference), which
     also stands in for ``ref`` without JAX; ``ref``/``pallas`` go to
-    :func:`affinity_valid`."""
+    :func:`affinity_valid`.  ``timers`` (a :class:`repro.obs.StageTimers`)
+    times the Pallas path's padding, launch and fetch."""
     if backend == "pallas" or (backend != "np" and HAS_JAX):
         if not HAS_JAX:
             raise ImportError("backend 'pallas' requires JAX")
+        if timers is not None and backend == "pallas":
+            return _timed_pallas(occ, aff, wmask, mem_used, max_mem, n_funcs,
+                                 f_mem, cap_pct, max_conc, interpret, timers)
         return np.asarray(affinity_valid(
             occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem,
             cap_pct, max_conc, backend=backend, interpret=interpret))

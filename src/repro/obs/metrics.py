@@ -22,7 +22,9 @@ attached, and the hot paths guard with a single ``is not None`` check
 from __future__ import annotations
 
 from bisect import bisect_left
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from time import perf_counter
+from typing import (Callable, ContextManager, Dict, List, Optional, Sequence,
+                    Tuple)
 
 #: default histogram bounds: quarter-decade geometric ladder, 1us .. ~56s
 #: (32 buckets + overflow) — wide enough for both stage timers (sub-ms)
@@ -216,36 +218,47 @@ class MetricsRegistry:
 
 
 class StageTimers:
-    """Wall-clock stage timers for the session hot path (mask build,
-    strategy select, shard route, state delta apply).
+    """The program's span-and-counter API: wall-clock stage spans on the
+    decision path (router, per-item decide, kernel wrapper, facade commit,
+    state delta apply) and the counters beside them.
 
-    Holders keep a ``None`` reference when profiling is off — the fast path
-    is one attribute load + ``is not None``.  When on, stages are *sampled*
-    1-in-``sample`` (deterministic round-robin counter, no rng — bit-
-    identity with timers off is preserved): call sites ask :meth:`sample`
-    *before* taking timestamps, so the unsampled passes pay one cheap
-    counter tick instead of two clock reads + a histogram insert.  That is
-    what keeps the enabled scheduler hot path under the 5% budget
-    (``overhead.py --obs``).  Each stage feeds one fixed-bucket histogram
-    (``sched.stage.<stage>_s``) — quantiles without storing samples; counts
-    reflect *sampled* observations.  Wall time deliberately lives only in
-    histograms, never in trace records: trace exports stay deterministic
-    under the simulator's virtual clock."""
+    Holders keep a ``None`` reference when profiling is off -- the fast path
+    is one attribute load + ``is not None``.  When on, call sites take a
+    span only when :meth:`sample` lets them: a deterministic 1-in-``sample``
+    round-robin counter, no rng, so decisions and rng draws are bit-identical
+    with timers on or off, and the unsampled passes pay one counter tick
+    instead of two clock reads + a histogram insert.  That is what keeps the
+    enabled scheduler hot path under the 5% budget (``overhead.py --obs``).
+
+    Each stage feeds one fixed-bucket histogram ``<prefix>.<stage>_s``
+    (counts reflect *sampled* spans).  Spans nest on the calling thread: a
+    span's time also counts toward its parent's inner seconds, reported as
+    ``<prefix>.<stage>_inner_s``, so a stage's mean self time is its
+    histogram's ``(sum - inner) / count``.  ``annotate`` is a factory of
+    context managers, ``annotate(name, **meta)`` -- the caller passes
+    ``jax.profiler.TraceAnnotation`` to put every span on the device trace's
+    clock as ``<prefix>.<stage>`` (this module never imports JAX).  Wall time
+    never enters the decision tracer's records: trace exports stay
+    deterministic under the simulator's virtual clock."""
 
     def __init__(self, registry: MetricsRegistry,
-                 prefix: str = "sched.stage", sample: int = 128):
+                 prefix: str = "sched.stage", sample: int = 128,
+                 annotate: Optional[Callable[..., ContextManager]] = None):
         if sample < 1 or (sample & (sample - 1)):
             raise ValueError("sample must be a power of two")
         self.registry = registry
         self.prefix = prefix
         self.mask = sample - 1
         self.tick = 0
+        self.annotate = annotate
         self._hist: Dict[str, Histogram] = {}
+        self._open: List[float] = []  # inner seconds of each open span
+        self._inner: Dict[str, float] = {}
+        registry.register_collector(prefix, lambda: {
+            f"{stage}_inner_s": s for stage, s in self._inner.items()})
 
     def sample(self) -> bool:
-        """Deterministic 1-in-``sample`` gate; call before timestamping.
-        ``tick``/``mask`` are public so the hottest call sites can inline
-        this counter advance and skip the method call."""
+        """Deterministic 1-in-``sample`` gate; call before a span."""
         t = (self.tick + 1) & self.mask
         self.tick = t
         return t == 0
@@ -256,3 +269,47 @@ class StageTimers:
             h = self.registry.histogram(f"{self.prefix}.{stage}_s")
             self._hist[stage] = h
         h.observe(dt)
+
+    def span(self, stage: str, **meta) -> "_Span":
+        """A context manager that times its block as ``stage``; with
+        ``annotate``, inside the trace span ``<prefix>.<stage>`` carrying
+        ``meta``."""
+        return _Span(self, stage, meta)
+
+    def count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to the counter ``<prefix>.<name>``."""
+        self.registry.counter(f"{self.prefix}.{name}").inc(n)
+
+
+class _Span:
+    """One span of :meth:`StageTimers.span` (a class, not a generator: it
+    costs less than half as much per span)."""
+
+    __slots__ = ("tm", "stage", "meta", "ann", "t0")
+
+    def __init__(self, tm: StageTimers, stage: str, meta: Dict):
+        self.tm = tm
+        self.stage = stage
+        self.meta = meta
+        self.ann = None
+
+    def __enter__(self) -> None:
+        tm = self.tm
+        if tm.annotate is not None:
+            self.ann = tm.annotate(f"{tm.prefix}.{self.stage}", **self.meta)
+            self.ann.__enter__()
+        tm._open.append(0.0)
+        self.t0 = perf_counter()
+
+    def __exit__(self, *exc) -> bool:
+        dt = perf_counter() - self.t0
+        tm = self.tm
+        opened = tm._open
+        inner = opened.pop()
+        if opened:
+            opened[-1] += dt
+        tm.observe(self.stage, dt)
+        tm._inner[self.stage] = tm._inner.get(self.stage, 0.0) + inner
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        return False

@@ -158,6 +158,7 @@ class Platform:
         self.lost_activations = 0  # activations lost to worker failures
         self.obs = obs
         self._tracer = None
+        self._timers = None
         if obs is not None:
             self.attach_obs(obs)
         if resilience is not None:
@@ -184,6 +185,7 @@ class Platform:
         made from that point on."""
         self.obs = obs
         self._tracer = obs.tracer if obs is not None else None
+        self._timers = obs.timers if obs is not None else None
         self.session.attach_obs(obs)
         if obs is not None:
             self._register_obs(obs)
@@ -367,14 +369,9 @@ class Platform:
             if tr is not None:
                 tr.decision(_t, function, None, zone)
             return Decision(function, self.registry[function].tag)
-        act = self.state.allocate(function, worker, self.registry)
-        if res is not None:
-            self._res_meta[act.activation_id] = (_tn, self.clock())
+        act, kind, cost = self._commit(function, worker,
+                                       _tn if res is not None else None)
         if self.pool is not None:
-            c, kind, cost = self.pool.acquire(
-                function, worker, self.clock(),
-                memory=act.memory, tag=act.tag)
-            self._containers[act.activation_id] = c.cid
             if tr is not None:
                 tr.invoke(act.activation_id, _t, function, worker,
                           kind, cost, zone)
@@ -386,6 +383,38 @@ class Platform:
                       "none", 0.0, zone)
         return Decision(function, act.tag, worker,
                         activation_id=act.activation_id)
+
+    def _commit(self, function: str, worker: str, tenant: Optional[str]):
+        """Record a decision: allocate in the state tables (the session's
+        tensors follow through the change feed) and, with a pool attached,
+        acquire the container.  Returns ``(activation, start kind, start
+        cost)``, the last two ``None`` without a pool.  ``tenant`` is
+        stamped for the resilience layer when given.  A sampled commit runs
+        in the ``commit`` span, the acquire in ``pool_acquire``."""
+        tm = self._timers
+        if tm is not None and tm.sample():
+            with tm.span("commit"):
+                return self._allocate(function, worker, tenant, tm)
+        return self._allocate(function, worker, tenant, None)
+
+    def _allocate(self, function: str, worker: str, tenant: Optional[str],
+                  tm):
+        act = self.state.allocate(function, worker, self.registry)
+        if tenant is not None:
+            self._res_meta[act.activation_id] = (tenant, self.clock())
+        if self.pool is None:
+            return act, None, None
+        if tm is not None:
+            with tm.span("pool_acquire"):
+                c, kind, cost = self.pool.acquire(
+                    function, worker, self.clock(),
+                    memory=act.memory, tag=act.tag)
+        else:
+            c, kind, cost = self.pool.acquire(
+                function, worker, self.clock(),
+                memory=act.memory, tag=act.tag)
+        self._containers[act.activation_id] = c.cid
+        return act, kind, cost
 
     def complete(self, decision_or_id: Union[Decision, str],
                  service_time: Optional[float] = None) -> Optional[Activation]:
@@ -554,13 +583,9 @@ class Platform:
             if w is None:
                 out[i] = Decision(f, reg[f].tag)
                 return
-            act = self.state.allocate(f, w, reg)
-            if res_b is not None:
-                self._res_meta[act.activation_id] = (_tn, self.clock())
+            act, kind, cost = self._commit(
+                f, w, _tn if res_b is not None else None)
             if self.pool is not None:
-                c, kind, cost = self.pool.acquire(
-                    f, w, self.clock(), memory=act.memory, tag=act.tag)
-                self._containers[act.activation_id] = c.cid
                 out[i] = Decision(f, act.tag, w,
                                   activation_id=act.activation_id,
                                   start_kind=kind, start_cost=cost)

@@ -1,4 +1,6 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret mode on CPU) vs ref.py."""
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -34,6 +36,50 @@ def test_affinity_kernel_matches_ref(W, T, F):
     ref = np.asarray(affinity_valid(*args, backend="ref"))
     out = np.asarray(affinity_valid(*args, backend="pallas", interpret=True))
     np.testing.assert_array_equal(ref, out)
+
+
+def test_affinity_wrapper_stages_and_bytes():
+    """With stage timers, the Pallas path records one each of its padding,
+    launch and fetch spans, counts the launch and the padded bytes it ships,
+    and returns what it returns untimed."""
+    from repro.kernels.affinity import affinity_valid_np
+    from repro.kernels.affinity.ops import _padded
+    from repro.obs import MetricsRegistry, StageTimers
+
+    rng = np.random.default_rng(3)
+    W, T, F = 37, 19, 5
+    occ = rng.integers(0, 3, (W, T)).astype(np.int32)
+    aff = rng.integers(-1, 2, (F, T)).astype(np.int8)
+    wmask = rng.random((F, W)) > 0.2
+    mem_used = (rng.random(W) * 100).astype(np.float32)
+    max_mem = np.full(W, 120, np.float32)
+    n_funcs = occ.sum(1).astype(np.int32)
+    f_mem = (rng.random(F) * 30).astype(np.float32)
+    cap = np.full(F, 1e9, np.float32)
+    conc = np.full(F, 2**30, np.int32)
+    args = (occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem, cap, conc)
+    names = []
+
+    def annotate(name, **_meta):
+        names.append(name)
+        return contextlib.nullcontext()
+
+    reg = MetricsRegistry()
+    tm = StageTimers(reg, sample=1, annotate=annotate)
+    timed = affinity_valid_np(*args, backend="pallas", interpret=True,
+                              timers=tm)
+    plain = affinity_valid_np(*args, backend="pallas", interpret=True)
+    assert isinstance(timed, np.ndarray) and timed.dtype == bool
+    np.testing.assert_array_equal(timed, plain)
+    assert names == ["sched.stage.valid_pad", "sched.stage.valid_launch",
+                     "sched.stage.valid_fetch"]
+    snap = reg.snapshot()
+    for stage in ("valid_pad", "valid_launch", "valid_fetch"):
+        assert snap[f"sched.stage.{stage}_s.count"] == 1
+    assert snap["sched.stage.valid_launches"] == 1
+    padded = _padded(*args)
+    assert len(padded) == 9
+    assert snap["sched.stage.valid_h2d_bytes"] == sum(a.nbytes for a in padded)
 
 
 @settings(max_examples=15, deadline=None)

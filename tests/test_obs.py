@@ -152,6 +152,62 @@ def test_stage_timers_sampling():
         StageTimers(reg, sample=3)  # not a power of two
 
 
+@pytest.mark.parametrize("annotated", [False, True])
+def test_stage_timers_span_and_count(annotated):
+    reg = MetricsRegistry()
+    opened = []
+
+    class Ann:
+        def __init__(self, name, **meta):
+            opened.append((name, meta))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    tm = StageTimers(reg, sample=1, annotate=Ann if annotated else None)
+    with tm.span("item_decide", decision=7):
+        with tm.span("mask_build"):
+            pass
+        with tm.span("valid_pad"):
+            pass
+    with pytest.raises(KeyError):  # a span closes when its block raises
+        with tm.span("commit"):
+            raise KeyError("f")
+    tm.count("valid_launches")
+    tm.count("valid_h2d_bytes", 2048)
+    tm.count("valid_h2d_bytes", 1024)
+    snap = reg.snapshot()
+    for stage in ("item_decide", "mask_build", "valid_pad", "commit"):
+        assert snap[f"sched.stage.{stage}_s.count"] == 1
+    assert snap["sched.stage.valid_launches"] == 1
+    assert snap["sched.stage.valid_h2d_bytes"] == 3072
+    # the children's time is the parent's inner time; leaves have none
+    h = reg.histogram
+    inner = snap["sched.stage.item_decide_inner_s"]
+    assert inner == pytest.approx(h("sched.stage.mask_build_s").sum
+                                  + h("sched.stage.valid_pad_s").sum)
+    assert 0 < inner <= h("sched.stage.item_decide_s").sum
+    assert snap["sched.stage.mask_build_inner_s"] == 0.0
+    # one trace span per span, named as the histogram without ``_s``, and
+    # only when the caller hands in an ``annotate``
+    assert opened == ([("sched.stage.item_decide", {"decision": 7}),
+                       ("sched.stage.mask_build", {}),
+                       ("sched.stage.valid_pad", {}),
+                       ("sched.stage.commit", {})] if annotated else [])
+
+
+def test_obs_imports_without_jax():
+    import subprocess
+    import sys
+
+    code = ("import sys; sys.modules['jax'] = None; import repro.obs; "
+            "repro.obs.StageTimers(repro.obs.MetricsRegistry(), sample=1)")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
 # --------------------------------------------------------------------------- #
 # tracer: records, exports, validator
 # --------------------------------------------------------------------------- #

@@ -9,6 +9,7 @@ described inside a fixture, never at import: only one process may load the
 TPU library, and every test worker imports this file.
 """
 import os
+import re
 
 import pytest
 
@@ -53,13 +54,16 @@ def _structs(one_chip, shapes_dtypes):
             for s, d in shapes_dtypes]
 
 
-def _assert_kernel_compiled(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel_compiled(compiled, name):
+    """A Pallas custom call, named for the kernel: the device trace's op
+    name, which the benchmark's reduction reads."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text)
 
 
-@pytest.mark.parametrize("R,W,T", SHAPES)
-def test_affinity_valid_kernel_compiles_for_v5e(one_chip, R, W, T):
-    args = _structs(one_chip, [
+def _valid_args(one_chip, R, W, T):
+    return _structs(one_chip, [
         ((R, T), jnp.int8),      # aff
         ((R, 1), jnp.float32),   # f_mem
         ((R, 1), jnp.float32),   # cap_pct
@@ -70,7 +74,24 @@ def test_affinity_valid_kernel_compiles_for_v5e(one_chip, R, W, T):
         ((1, W), jnp.int32),     # n_funcs
         ((R, W), jnp.int8),      # wmask
     ])
-    _assert_kernel_compiled(affinity_valid_kernel.lower(*args).compile())
+
+
+@pytest.mark.parametrize("R,W,T", SHAPES)
+def test_affinity_valid_kernel_compiles_for_v5e(one_chip, R, W, T):
+    args = _valid_args(one_chip, R, W, T)
+    _assert_kernel_compiled(affinity_valid_kernel.lower(*args).compile(),
+                            "affinity_valid_kernel")
+
+
+def test_kernel_name_outlives_a_renamed_wrapper(one_chip):
+    """The op keeps the kernel's own name under a jitted wrapper of
+    another name."""
+    def renamed(*args):
+        return affinity_valid_kernel.__wrapped__(*args)
+
+    args = _valid_args(one_chip, *SHAPES[0])
+    _assert_kernel_compiled(jax.jit(renamed).lower(*args).compile(),
+                            "affinity_valid_kernel")
 
 
 @pytest.mark.parametrize("R,W,T", SHAPES)
@@ -88,4 +109,5 @@ def test_bulk_decide_kernel_compiles_for_v5e(one_chip, R, W, T):
         ((R, W), jnp.int8),      # wmask
         ((R, W), jnp.int32),     # warm
     ])
-    _assert_kernel_compiled(bulk_decide_kernel.lower(*args).compile())
+    _assert_kernel_compiled(bulk_decide_kernel.lower(*args).compile(),
+                            "bulk_decide_kernel")
