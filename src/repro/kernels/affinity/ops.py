@@ -1,5 +1,6 @@
-"""Public wrapper around the affinity kernel: padding, backend dispatch,
-unpadding.  The caller names the backend; nothing is picked for it.
+"""Public wrapper around the affinity kernel: backend dispatch, and for
+``pallas`` one jitted program per call that pads, runs the kernel and
+unpads on the device.  The caller names the backend; nothing is picked for it.
 ``backend="pallas"`` compiles the kernel for the TPU and raises on any other
 JAX backend unless the caller asks for the Pallas interpreter
 (``interpret=True``, the tests' CPU path).  Without JAX installed at all
@@ -8,12 +9,15 @@ JAX backend unless the caller asks for the Pallas interpreter
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from .ref_np import NO_CAP, NO_CONC, affinity_valid_ref_np
 
 try:
     import jax
+    import jax.numpy as jnp
 
     from .kernel import BF, BW, T_ALIGN, affinity_valid_kernel
     from .ref import affinity_valid_ref
@@ -69,7 +73,7 @@ def affinity_valid(
         raise ImportError(
             "affinity_valid requires JAX; use affinity_valid_np for the "
             "numpy fallback")
-    F, W, cap_pct, max_conc = _checked(occ, aff, cap_pct, max_conc)
+    cap_pct, max_conc = _checked(occ, aff, cap_pct, max_conc)
     if backend == "ref":
         return affinity_valid_ref(
             occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem, cap_pct, max_conc
@@ -77,17 +81,16 @@ def affinity_valid(
     if backend != "pallas":
         raise ValueError(f"unknown backend {backend!r}")
     check_pallas(interpret)
-    valid = affinity_valid_kernel(
-        *_padded(occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem,
+    return _valid_program(
+        *_inputs(occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem,
                  cap_pct, max_conc),
         interpret=interpret,
     )
-    return valid[:F, :W].astype(bool)
 
 
 def _checked(occ, aff, cap_pct, max_conc):
-    """``F``, ``W`` and the per-row limits, unlimited where not given."""
-    W, T = np.shape(occ)
+    """The per-row limits, unlimited where not given."""
+    T = np.shape(occ)[1]
     F = np.shape(aff)[0]
     if np.shape(aff)[1] != T:
         raise ValueError(f"tag axes differ: occ {T}, aff {np.shape(aff)[1]}")
@@ -95,48 +98,99 @@ def _checked(occ, aff, cap_pct, max_conc):
         cap_pct = np.full((F,), NO_CAP, np.float32)
     if max_conc is None:
         max_conc = np.full((F,), NO_CONC, np.int32)
-    return F, W, cap_pct, max_conc
+    return cap_pct, max_conc
 
 
-def _padded(occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem, cap_pct,
+def _blocks(buf, F, W, T):
+    """The two blocks of the packed input ``buf`` (numpy views on the host,
+    slices in the program): per worker ``[T + 3, W]`` (the tag counts,
+    ``n_funcs``, the float32 bits of ``mem_used`` and ``max_mem``) and per
+    row ``[F, T + 3 + W]`` (the affinity row, the float32 bits of ``f_mem``
+    and ``cap_pct``, ``max_conc``, the worker mask)."""
+    n = (T + 3) * W
+    return buf[:n].reshape(T + 3, W), buf[n:].reshape(F, T + 3 + W)
+
+
+def _bits(x):
+    return np.ascontiguousarray(x, np.float32).view(np.int32)
+
+
+def _inputs(occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem, cap_pct,
             max_conc):
-    """The kernel's nine inputs, padded on the host to tile-aligned
-    shapes."""
+    """The kernel's nine inputs at their real shapes, packed into one int32
+    host array so that a call ships one buffer (each array shipped is a
+    host-device round trip of its own), and its ``(F, W, T)``.  int8 and
+    bool values are widened and float32 values carried as their bits, both
+    exact; the only casts are the kernel's dtypes (float64 memory to
+    float32).  The padding is :func:`_valid_program`'s."""
     W, T = np.shape(occ)
     F = np.shape(aff)[0]
-    Fp, Wp, Tp = round_up(max(F, 1), BF), round_up(max(W, 1), BW), round_up(max(T, 1), T_ALIGN)
-    return (
-        pad_to(aff, (Fp, Tp), np.int8),
-        pad_to(np.reshape(f_mem, (F, 1)), (Fp, 1), np.float32),
-        pad_to(np.reshape(cap_pct, (F, 1)), (Fp, 1), np.float32, NO_CAP),
-        pad_to(np.reshape(max_conc, (F, 1)), (Fp, 1), np.int32, NO_CONC),
-        pad_to(occ, (Wp, Tp), np.int32),
-        pad_to(np.reshape(mem_used, (1, W)), (1, Wp), np.float32),
-        pad_to(np.reshape(max_mem, (1, W)), (1, Wp), np.float32),
-        pad_to(np.reshape(n_funcs, (1, W)), (1, Wp), np.int32),
-        pad_to(wmask, (Fp, Wp), np.int8),
-    )
+    buf = np.empty((T + 3) * W + F * (T + 3 + W), np.int32)
+    per_w, per_r = _blocks(buf, F, W, T)
+    per_w[:T] = np.transpose(occ)
+    per_w[T] = n_funcs
+    per_w[T + 1] = _bits(mem_used)
+    per_w[T + 2] = _bits(max_mem)
+    per_r[:, :T] = aff
+    per_r[:, T] = _bits(f_mem)
+    per_r[:, T + 1] = _bits(cap_pct)
+    per_r[:, T + 2] = max_conc
+    per_r[:, T + 3:] = wmask
+    return buf, (F, W, T)
+
+
+if HAS_JAX:
+
+    @functools.partial(jax.jit, static_argnames=("shape", "interpret"))
+    def _valid_program(buf, shape, *, interpret):
+        """One device program per validity call: unpack ``buf``, pad to
+        tile-aligned shapes, run the kernel, slice back to ``[F, W]`` bool.
+        Compiled once per ``shape`` (F, W, T)."""
+        F, W, T = shape
+        per_w, per_r = _blocks(buf, F, W, T)
+        Fp, Wp, Tp = (round_up(max(F, 1), BF), round_up(max(W, 1), BW),
+                      round_up(max(T, 1), T_ALIGN))
+
+        def f32(x):
+            return jax.lax.bitcast_convert_type(x, jnp.float32)
+
+        def pad(x, to, fill=0):
+            return jnp.pad(x, [(0, n - m) for m, n in zip(x.shape, to)],
+                           constant_values=fill)
+
+        valid = affinity_valid_kernel(
+            pad(per_r[:, :T].astype(jnp.int8), (Fp, Tp)),
+            pad(f32(per_r[:, T:T + 1]), (Fp, 1)),
+            pad(f32(per_r[:, T + 1:T + 2]), (Fp, 1), NO_CAP),
+            pad(per_r[:, T + 2:T + 3], (Fp, 1), NO_CONC),
+            pad(per_w[:T].T, (Wp, Tp)),
+            pad(f32(per_w[T + 1:T + 2]), (1, Wp)),
+            pad(f32(per_w[T + 2:T + 3]), (1, Wp)),
+            pad(per_w[T:T + 1], (1, Wp)),
+            pad(per_r[:, T + 3:].astype(jnp.int8), (Fp, Wp)),
+            interpret=interpret)
+        return valid[:F, :W].astype(bool)
 
 
 def _timed_pallas(occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem,
                   cap_pct, max_conc, interpret: bool, tm) -> np.ndarray:
     """:func:`affinity_valid`'s Pallas path to a host array, its stages
-    timed by ``tm`` (a :class:`repro.obs.StageTimers`): ``valid_pad`` (host
-    padding), ``valid_launch`` (the kernel call up to its return: transfers
-    of the padded inputs and dispatch), ``valid_fetch`` (device wait, unpad,
-    copy to the host); counters ``valid_launches`` and ``valid_h2d_bytes``
-    (the padded inputs' bytes)."""
-    F, W, cap_pct, max_conc = _checked(occ, aff, cap_pct, max_conc)
+    timed by ``tm`` (a :class:`repro.obs.StageTimers`): ``valid_pad`` (the
+    host packing, :func:`_inputs`), ``valid_launch`` (the program call up
+    to its return: the one transfer and dispatch), ``valid_fetch`` (device
+    wait and the one copy of the ``[F, W]`` bools to the host); counters
+    ``valid_launches`` and ``valid_h2d_bytes`` (the bytes shipped)."""
+    cap_pct, max_conc = _checked(occ, aff, cap_pct, max_conc)
     check_pallas(interpret)
     with tm.span("valid_pad"):
-        args = _padded(occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem,
-                       cap_pct, max_conc)
+        buf, shape = _inputs(occ, aff, wmask, mem_used, max_mem, n_funcs,
+                             f_mem, cap_pct, max_conc)
     tm.count("valid_launches")
-    tm.count("valid_h2d_bytes", sum(a.nbytes for a in args))
+    tm.count("valid_h2d_bytes", buf.nbytes)
     with tm.span("valid_launch"):
-        valid = affinity_valid_kernel(*args, interpret=interpret)
+        valid = _valid_program(buf, shape, interpret=interpret)
     with tm.span("valid_fetch"):
-        return np.asarray(valid[:F, :W].astype(bool))
+        return np.asarray(valid)
 
 
 def affinity_valid_np(
@@ -159,7 +213,7 @@ def affinity_valid_np(
     scheduling session uses (bit-identical to the jnp reference), which
     also stands in for ``ref`` without JAX; ``ref``/``pallas`` go to
     :func:`affinity_valid`.  ``timers`` (a :class:`repro.obs.StageTimers`)
-    times the Pallas path's padding, launch and fetch."""
+    times the Pallas path's host packing, launch and fetch."""
     if backend == "pallas" or (backend != "np" and HAS_JAX):
         if not HAS_JAX:
             raise ImportError("backend 'pallas' requires JAX")

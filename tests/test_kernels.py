@@ -19,9 +19,18 @@ from repro.kernels.mamba_scan import selective_scan, selective_scan_ref
 # --------------------------------------------------------------------------- #
 
 
+# divimp's per-item calls: a zone hop (W = 3072) and a delegated heavy
+# decision (W = 6144), one or two blocks of 4-5 tag columns; then shapes
+# where no axis is a tile multiple
 @pytest.mark.parametrize("W,T,F", [(1, 1, 1), (7, 3, 5), (37, 19, 23),
-                                   (128, 128, 128), (130, 5, 257)])
+                                   (128, 128, 128), (130, 5, 257),
+                                   (3072, 5, 2), (6144, 4, 2), (3072, 5, 1),
+                                   (6144, 5, 1), (1000, 37, 3)])
 def test_affinity_kernel_matches_ref(W, T, F):
+    """The Pallas program equals the jnp reference and the numpy twin bit
+    for bit: ``[F, W]`` bool."""
+    from repro.kernels.affinity import affinity_valid_np
+
     rng = np.random.default_rng(W * 1000 + T * 10 + F)
     occ = rng.integers(0, 3, (W, T)).astype(np.int32)
     aff = rng.integers(-1, 2, (F, T)).astype(np.int8)
@@ -35,15 +44,17 @@ def test_affinity_kernel_matches_ref(W, T, F):
     args = (occ, aff, wmask, mem_used, max_mem, n_funcs, f_mem, cap, conc)
     ref = np.asarray(affinity_valid(*args, backend="ref"))
     out = np.asarray(affinity_valid(*args, backend="pallas", interpret=True))
+    twin = affinity_valid_np(*args, backend="np")
+    assert out.dtype == bool and out.shape == (F, W)
     np.testing.assert_array_equal(ref, out)
+    np.testing.assert_array_equal(twin, out)
 
 
 def test_affinity_wrapper_stages_and_bytes():
-    """With stage timers, the Pallas path records one each of its padding,
-    launch and fetch spans, counts the launch and the padded bytes it ships,
-    and returns what it returns untimed."""
+    """With stage timers, the Pallas path records one each of its host
+    packing, launch and fetch spans, counts the launch and the bytes of the
+    unpadded inputs it ships, and returns what it returns untimed."""
     from repro.kernels.affinity import affinity_valid_np
-    from repro.kernels.affinity.ops import _padded
     from repro.obs import MetricsRegistry, StageTimers
 
     rng = np.random.default_rng(3)
@@ -51,8 +62,8 @@ def test_affinity_wrapper_stages_and_bytes():
     occ = rng.integers(0, 3, (W, T)).astype(np.int32)
     aff = rng.integers(-1, 2, (F, T)).astype(np.int8)
     wmask = rng.random((F, W)) > 0.2
-    mem_used = (rng.random(W) * 100).astype(np.float32)
-    max_mem = np.full(W, 120, np.float32)
+    mem_used = rng.random(W) * 100  # float64, as the session keeps it
+    max_mem = np.full(W, 120.0)
     n_funcs = occ.sum(1).astype(np.int32)
     f_mem = (rng.random(F) * 30).astype(np.float32)
     cap = np.full(F, 1e9, np.float32)
@@ -70,6 +81,7 @@ def test_affinity_wrapper_stages_and_bytes():
                               timers=tm)
     plain = affinity_valid_np(*args, backend="pallas", interpret=True)
     assert isinstance(timed, np.ndarray) and timed.dtype == bool
+    assert timed.shape == (F, W)
     np.testing.assert_array_equal(timed, plain)
     assert names == ["sched.stage.valid_pad", "sched.stage.valid_launch",
                      "sched.stage.valid_fetch"]
@@ -77,9 +89,11 @@ def test_affinity_wrapper_stages_and_bytes():
     for stage in ("valid_pad", "valid_launch", "valid_fetch"):
         assert snap[f"sched.stage.{stage}_s.count"] == 1
     assert snap["sched.stage.valid_launches"] == 1
-    padded = _padded(*args)
-    assert len(padded) == 9
-    assert snap["sched.stage.valid_h2d_bytes"] == sum(a.nbytes for a in padded)
+    # one int32 buffer of the inputs at their real shapes, nothing padded
+    # to the kernel's tiles: per worker T tag counts, n_funcs and the two
+    # memory figures; per row the T affinities, three limits, W mask bits
+    shipped = 4 * (W * (T + 3) + F * (T + 3 + W))
+    assert snap["sched.stage.valid_h2d_bytes"] == shipped
 
 
 @settings(max_examples=15, deadline=None)

@@ -19,9 +19,12 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.affinity.bulk_kernel import bulk_decide_kernel
 from repro.kernels.affinity.kernel import affinity_valid_kernel
+from repro.kernels.affinity.ops import _valid_program
 
 # (R, W, T): a wave's distinct-function rows, cluster workers, tag columns
 SHAPES = [(128, 16384, 128), (512, 16384, 1024), (128, 65536, 128)]
+# (B, W, T) of divimp's per-item calls: a zone hop and a delegated decision
+PER_ITEM = [(1, 3072, 5), (1, 6144, 5), (2, 3072, 5), (2, 6144, 4)]
 
 
 @pytest.fixture(scope="module")
@@ -55,11 +58,13 @@ def _structs(one_chip, shapes_dtypes):
 
 
 def _assert_kernel_compiled(compiled, name):
-    """A Pallas custom call, named for the kernel: the device trace's op
-    name, which the benchmark's reduction reads."""
+    """One Pallas custom call, named for the kernel: the device trace's op
+    name, which the benchmark's reduction reads once per launch."""
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text)
+    calls = re.findall(rf"%{name}(?:\.\d+)? = .*custom-call\(", text)
+    assert len(calls) == 1, calls
+    return text
 
 
 def _valid_args(one_chip, R, W, T):
@@ -92,6 +97,20 @@ def test_kernel_name_outlives_a_renamed_wrapper(one_chip):
     args = _valid_args(one_chip, *SHAPES[0])
     _assert_kernel_compiled(jax.jit(renamed).lower(*args).compile(),
                             "affinity_valid_kernel")
+
+
+@pytest.mark.parametrize("B,W,T", PER_ITEM)
+def test_per_item_program_compiles_for_v5e(one_chip, B, W, T):
+    """The per-item path's one program at its real, unaligned shapes: the
+    unpacking, the padding, one kernel and the unpad compile together, and
+    the result leaves the device as ``pred[B, W]``."""
+    n = (T + 3) * W + B * (T + 3 + W)  # the packed int32 input
+    buf, = _structs(one_chip, [((n,), jnp.int32)])
+    text = _assert_kernel_compiled(
+        _valid_program.lower(buf, (B, W, T), interpret=False).compile(),
+        "affinity_valid_kernel")
+    root = re.search(r"ENTRY .*?ROOT [^\n]*", text, re.S).group(0)
+    assert re.search(rf"ROOT \S+ = pred\[{B},{W}\]", root), root
 
 
 @pytest.mark.parametrize("R,W,T", SHAPES)
