@@ -9,8 +9,8 @@ import math
 
 from bench import deploy, harness, traffic
 
-# The flat deployment's cell: its configuration and mix are kept as data
-# for a later BENCHMARK.json entry, and the CPU tests run it.
+# The flat deployment's cell as the CPU tests run it where BENCHMARK.json
+# has no entry of that name; where it has one, ``cell`` returns that.
 AZURE_CELL = {"name": "azure16k-steady", "config": "azure-region-16k",
               "traffic": "azure-poisson-steady", "chips": 1}
 
@@ -47,8 +47,10 @@ def tiny_mix(name: str) -> dict:
 
 
 def cell(spec: dict, name: str) -> dict:
-    """The cell ``name`` of ``spec``, or the flat deployment's cell."""
-    if name == AZURE_CELL["name"]:
+    """The cell ``name`` of ``spec``, or, where ``spec`` has no such cell,
+    the flat deployment's."""
+    if name == AZURE_CELL["name"] and all(
+            c["name"] != name for c in spec["workloads"]):
         return AZURE_CELL
     return harness.find_cell(spec, name)
 
